@@ -196,7 +196,7 @@ def run(smoke: bool = False, scale: float = 0.3, workers: int = 32,
         indices.setdefault(id(inst.target), SubgraphIndex.build(inst.target))
 
     base = EngineConfig(n_workers=workers, expand_width=4)
-    interpret = kops.resolve_interpret(None)
+    interpret = kops.resolve_interpret()
 
     jnp_res, t_jnp = _sweep(base, instances, indices)
     total_states = sum(r["states"] for r in jnp_res.values())

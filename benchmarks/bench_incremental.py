@@ -125,7 +125,7 @@ def run(n_t: int, avg_deg: float, n_steps: int, seed: int,
         use_pallas: bool, check_every: int) -> dict:
     cfg = EngineConfig(n_workers=4, expand_width=2, step_backend="auto",
                        use_pallas=use_pallas)
-    interpret = kops.resolve_interpret(None)
+    interpret = kops.resolve_interpret()
     gate = not (use_pallas and interpret)  # interpret-mode pallas is exempt
 
     tgt = graphgen.power_law_graph(

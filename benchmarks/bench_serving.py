@@ -142,7 +142,7 @@ def run(n_queries: int, baseline_n: int, lanes: int, window_ms: float,
         seed: int, use_pallas: bool) -> dict:
     cfg = EngineConfig(n_workers=4, expand_width=2, step_backend="auto",
                        use_pallas=use_pallas)
-    interpret = kops.resolve_interpret(None)
+    interpret = kops.resolve_interpret()
     gate = not (use_pallas and interpret)  # interpret-mode pallas is exempt
 
     index, queries = build_corpus(n_queries, seed)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core import extend, frontier, scheduler
@@ -85,7 +84,9 @@ class EngineConfig:
       use_pallas: with ``step_backend="jnp"``, route only the
         candidate-bitmap AND through `repro.kernels.candidate_mask` (the
         pre-seam kerneling point; the fused backend subsumes it); with
-        ``"csr"``, route the CSR walk through `repro.kernels.csr_extend`.
+        ``"csr"``, route the CSR walk through `repro.kernels.csr_extend`
+        (and a sparse index's AC sweeps at ``prepare`` through
+        `repro.kernels.domain_ac.csr_arc_sweep`).
       store_used: keep per-entry used-bitmaps on the stack (True) or
         recompute them from the mapping at expansion time (False; refuted
         as a default by §Perf iteration 7 — see EXPERIMENTS.md §Perf).
@@ -475,12 +476,12 @@ def make_sharded_engine_fn(
             f"{axis!r} size {n_dev}; round up to a multiple"
         )
     specs = state_partition_specs(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_sharded_device_loop, cfg, axis),
         mesh=mesh,
         in_specs=(plan_partition_specs_for(cfg, n_t, csr_only), specs),
         out_specs=specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -690,12 +691,12 @@ def make_partitioned_engine_fn(
         )
     st_specs = state_partition_specs(axis)
     sp_specs = spill_partition_specs(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_part_sharded_device_loop, cfg, axis),
         mesh=mesh,
         in_specs=(extend.part_plan_partition_specs(), st_specs, sp_specs),
         out_specs=(st_specs, sp_specs),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -556,10 +556,13 @@ def pop_lowest_bit(cand: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.nda
 
 
 def bit_row(v: jnp.ndarray, w: int) -> jnp.ndarray:
-    """One-hot ``[w]`` uint32 bitmap with bit ``v`` set."""
+    """One-hot ``[w]`` uint32 bitmap with bit ``v`` set (all-zero when
+    ``v`` is out of range).  A compare, not a scatter: vmapped over
+    4,096 lanes of 1,034 words, the scatter form lost used-set bits on a
+    TPU v5e, and matches repeated a target node."""
     word = v // WORD_BITS
     bit = jnp.uint32(1) << (v % WORD_BITS).astype(jnp.uint32)
-    return jnp.zeros((w,), jnp.uint32).at[word].set(bit)
+    return jnp.where(jnp.arange(w) == word, bit, jnp.uint32(0))
 
 
 def compute_cand_jnp(

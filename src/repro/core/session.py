@@ -505,6 +505,7 @@ def prepare_query(
     p_pad: Optional[int] = None,
     max_parents: Optional[int] = None,
     seed_edge=None,
+    use_pallas: bool = False,
 ) -> Query:
     """Compile ``pattern`` against ``index`` into a bucketed :class:`Query`.
 
@@ -517,7 +518,8 @@ def prepare_query(
     Preparation routes by the index layout (DESIGN.md §11): a **sparse**
     index (``SubgraphIndex.build(graph, sparse=True)``) compiles through
     :func:`~repro.core.plan.build_csr_plan` — domains come from the
-    CSR-native fixpoint and the resulting plan is CSR-only.
+    CSR-native fixpoint (through the `csr_arc_sweep` kernel when
+    ``use_pallas``) and the resulting plan is CSR-only.
     """
     index = SubgraphIndex.build(index)
     t0 = time.perf_counter()
@@ -531,6 +533,7 @@ def prepare_query(
             w=index.w,
             seed_edge=seed_edge,
             planes=index.csr_planes(),
+            use_pallas=use_pallas,
         )
     else:
         plan = build_plan(
@@ -894,7 +897,7 @@ class Enumerator:
             )
         q = prepare_query(
             pattern, idx, variant=variant or self.variant, name=name,
-            seed_edge=seed_edge,
+            seed_edge=seed_edge, use_pallas=self.config.use_pallas,
         )
         extend.validate_backend_for_plan(self.config, q.plan)
         return q

@@ -15,9 +15,10 @@ Kernels:
                      scalar-prefetch-indexed adjacency-row DMA + wide AND
                      (the step_backend="jnp" + use_pallas kerneling point)
   csr_extend       — the sparse expansion step (DESIGN.md §6.4): scalar-
-                     prefetched CSR segment bounds, pl.ds neighbor loads,
-                     sorted-intersection instead of the dense AND-tree
-                     (the step_backend="csr" + use_pallas kerneling point)
+                     prefetched CSR segment bounds, segments DMA'd into
+                     SMEM, a scalar-unit sorted intersection instead of the
+                     dense AND-tree (the step_backend="csr" + use_pallas
+                     kerneling point)
   domain_ac        — RI-DS arc-consistency row filter (SDDMM-shaped)
   popcount_reduce  — per-row popcounts (domain sizes, match stats)
   flash_attention  — fused causal online-softmax attention (beyond-paper;

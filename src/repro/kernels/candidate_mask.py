@@ -19,8 +19,9 @@ TPU mapping
   pipeline DMAs into VMEM next.  This is the TPU-native form of the paper's
   pointer-chasing adjacency-list walk: the DMA engine chases the indices
   while the VPU ANDs the previous row.
-* Block shapes are ``(1, w)`` with ``w`` padded to a multiple of 128 lanes
-  (uint32 words), so each AND is a full-width VPU op; the running candidate
+* Block shapes are ``(1, w)`` rows of ``[rows, 1, w]`` views with ``w``
+  padded to a multiple of 128 lanes (uint32 words), so each AND is a
+  full-width VPU op; the running candidate
   bitmap lives in the output block in VMEM across the ``mp`` grid steps
   (same output index for all j ⇒ accumulation without HBM round-trips).
 
@@ -65,13 +66,9 @@ def candidate_mask(
     pos: jnp.ndarray,  # [b] int32
     row_idx: jnp.ndarray,  # [b, mp] int32 (unused slots -> n_rows)
     used: jnp.ndarray,  # [b, w] uint32
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
-    """Jit'd wrapper; pads the word dimension and invokes the kernel.
-
-    ``interpret=True`` executes the kernel body in Python on CPU (the
-    validation mode for this container); on TPU pass ``interpret=False``.
-    """
+    """Jit'd wrapper; pads the word dimension and invokes the kernel."""
     b, w = used.shape
     mp = row_idx.shape[1]
     wp = pad_words(w)
@@ -83,33 +80,38 @@ def candidate_mask(
 
     grid = (b, mp + 1)
 
+    # bitmaps viewed as [rows, 1, wp] and the row table flattened to 1-D,
+    # as in `repro.kernels.extend_step`, so the chip's compiler accepts them
     def dom_map(l, j, pos_s, idx_s):
-        return (pos_s[l], 0)
+        return (pos_s[l], 0, 0)
 
     def row_map(l, j, pos_s, idx_s):
         # j == 0 is the init step; feed the neutral row (index n_rows).
         jj = jnp.maximum(j - 1, 0)
-        return (jnp.where(j == 0, rows.shape[0] - 1, idx_s[l, jj]), 0)
+        return (jnp.where(j == 0, rows.shape[0] - 1, idx_s[l * mp + jj]), 0, 0)
 
     def lane_map(l, j, pos_s, idx_s):
-        return (l, 0)
+        return (l, 0, 0)
 
+    bitmap = functools.partial(pl.BlockSpec, (None, 1, wp))
     out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, wp), dom_map),
-                pl.BlockSpec((1, wp), row_map),
-                pl.BlockSpec((1, wp), lane_map),
-            ],
-            out_specs=pl.BlockSpec((1, wp), lane_map),
+            in_specs=[bitmap(dom_map), bitmap(row_map), bitmap(lane_map)],
+            out_specs=bitmap(lane_map),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, wp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, wp), jnp.uint32),
         interpret=interpret,
-    )(pos.astype(jnp.int32), row_idx.astype(jnp.int32), dom_bits, rows, used)
-    return out[:, :w]
+    )(
+        pos.astype(jnp.int32),
+        row_idx.astype(jnp.int32).reshape(b * mp),
+        dom_bits[:, None, :],
+        rows[:, None, :],
+        used[:, None, :],
+    )
+    return out[:, 0, :w]
 
 
 def flatten_adj_rows(adj_bits: jnp.ndarray) -> jnp.ndarray:

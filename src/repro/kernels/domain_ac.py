@@ -24,13 +24,12 @@ Two granularities:
   back to per-arc kernels.
 * :func:`csr_arc_sweep` — the same sweep over **CSR planes** (DESIGN.md
   §11): no dense ``[n_planes, n_t, w]`` operand exists, so each grid step
-  walks a row tile's neighbor segments with ``pl.ds`` dynamic slices of the
-  flat ``indices`` block (the `csr_extend` load pattern) and any-reduces
-  the mask bit tests per row.  The per-plane segment bounds arrive as
-  ``(1, tr)`` operand blocks whose ``index_map`` chases the
-  scalar-prefetched ``arc_row`` table.  Scalar-prefetch again means no
-  vmap rule — batched CSR fixpoints use the jnp oracle
-  (`repro.kernels.ref.csr_arc_sweep_ref`).
+  walks a row tile's neighbor segments on the scalar unit, out of an SMEM
+  window of the HBM-resident flat ``indices``, and tests each neighbor's
+  bit in the arc's mask.  The per-plane segment bounds arrive as SMEM
+  tiles whose ``index_map`` chases the scalar-prefetched ``arc_row``
+  table.  Scalar-prefetch again means no vmap rule — batched CSR
+  fixpoints use the jnp oracle (`repro.kernels.ref.csr_arc_sweep_ref`).
 """
 
 from __future__ import annotations
@@ -44,7 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.candidate_mask import pad_words
-from repro.kernels.csr_extend import SENTINEL
+from repro.kernels.csr_extend import DMA_WORDS, SENTINEL
 
 ROW_TILE = 256
 
@@ -58,7 +57,8 @@ def _kernel(rows_ref, mask_ref, out_ref):
 def adjacency_any(
     rows: jnp.ndarray,  # [n_t, w] uint32
     mask: jnp.ndarray,  # [w] uint32
-    interpret: bool = True,
+    *,
+    interpret: bool,
     row_tile: int = ROW_TILE,
 ) -> jnp.ndarray:
     """Per-row any-bit test of ``rows ∧ mask`` -> ``[n_t]`` int32 {0,1}."""
@@ -84,7 +84,7 @@ def adjacency_any(
 
 
 def _sweep_kernel(arc_row_ref, adj_ref, mask_ref, out_ref):
-    hit = (adj_ref[0] & mask_ref[...]) != 0  # [tr, w] & [1, w] -> [tr, w]
+    hit = (adj_ref[...] & mask_ref[...]) != 0  # [tr, wp] & [1, wp] -> [tr, wp]
     out_ref[...] = jnp.any(hit, axis=-1)[None, :].astype(jnp.int32)
 
 
@@ -93,7 +93,8 @@ def arc_any_sweep(
     adj_flat: jnp.ndarray,  # [n_planes, n_t, w] uint32 (label-major planes)
     arc_row: jnp.ndarray,  # [n_arcs] int32 plane index per arc
     masks: jnp.ndarray,  # [n_arcs, w] uint32 (D(q) bitmap per arc)
-    interpret: bool = True,
+    *,
+    interpret: bool,
     row_tile: int = ROW_TILE,
 ) -> jnp.ndarray:
     """All arcs of one AC sweep in one kernel call.
@@ -111,14 +112,17 @@ def arc_any_sweep(
     adj_p = jnp.pad(adj_flat, ((0, 0), (0, n_pad - n_t), (0, wp - w)))
     masks_p = jnp.pad(masks, ((0, 0), (0, wp - w)))
 
+    # masks and flags are viewed as [n_arcs, 1, x] and blocked (squeezed,
+    # 1, x): a (1, x) block of an [n_arcs, x] array breaks the TPU rule that
+    # a block's last two dims tile by (8, 128) or span the array.
     def adj_map(a, i, arc_row_s):
         return (arc_row_s[a], i, 0)
 
     def mask_map(a, i, arc_row_s):
-        return (a, 0)
+        return (a, 0, 0)
 
     def out_map(a, i, arc_row_s):
-        return (a, i)
+        return (a, 0, i)
 
     out = pl.pallas_call(
         _sweep_kernel,
@@ -126,39 +130,61 @@ def arc_any_sweep(
             num_scalar_prefetch=1,
             grid=(n_arcs, n_pad // tr),
             in_specs=[
-                pl.BlockSpec((1, tr, wp), adj_map),
-                pl.BlockSpec((1, wp), mask_map),
+                pl.BlockSpec((None, tr, wp), adj_map),
+                pl.BlockSpec((None, 1, wp), mask_map),
             ],
-            out_specs=pl.BlockSpec((1, tr), out_map),
+            out_specs=pl.BlockSpec((None, 1, tr), out_map),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_arcs, n_pad), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n_arcs, 1, n_pad), jnp.int32),
         interpret=interpret,
-    )(arc_row.astype(jnp.int32), adj_p, masks_p)
-    return out[:, :n_t]
+    )(arc_row.astype(jnp.int32), adj_p, masks_p[:, None, :])
+    return out[:, 0, :n_t]
 
 
 def _csr_sweep_kernel(
-    arc_row_ref, sst_ref, sln_ref, ind_ref, mask_ref, out_ref, *, deg_cap: int
+    arc_row_ref, sst_ref, sln_ref, mask_ref, ind_hbm, out_ref, win, sem,
+    *, deg_cap: int,
 ):
+    """One (arc, row tile) step: every row's segment is walked on the scalar
+    unit out of ``win``, an SMEM window of the flat ``indices``; the window
+    is refilled (one DMA, 1024-word aligned) whenever a row runs past it."""
     tr = out_ref.shape[1]
-    wp = mask_ref.shape[1]
-    offs = lax.iota(jnp.int32, deg_cap)
-    row_iota = lax.iota(jnp.int32, tr)
-    mask = mask_ref[0, :]  # [wp]
+    cap = win.shape[0]
+    n_bits = mask_ref.shape[1] * 32
+    row_iota = lax.broadcasted_iota(jnp.int32, (1, tr), 1)
 
-    def row(j, acc):
+    def row(j, carry):
+        acc, lo, hi = carry
         s = sst_ref[0, j]
-        ln = jnp.minimum(sln_ref[0, j], deg_cap)
-        u = ind_ref[0, pl.ds(s, deg_cap)]  # [deg_cap]
-        k_on = offs < ln
-        u_c = jnp.clip(u, 0, wp * 32 - 1)
-        word = u_c // 32
-        bit = (u_c % 32).astype(jnp.uint32)
-        in_dom = (jnp.take(mask, word) >> bit) & jnp.uint32(1)
-        hit = jnp.any(k_on & (in_dom != 0))
-        return jnp.where(row_iota == j, hit.astype(jnp.int32), acc)
+        e = s + jnp.clip(sln_ref[0, j], 0, deg_cap)
+        refill = (e > s) & ((s < lo) | (e > hi))
+        line = pl.multiple_of((s // DMA_WORDS) * DMA_WORDS, DMA_WORDS)
 
-    out_ref[...] = lax.fori_loop(0, tr, row, jnp.zeros((tr,), jnp.int32))[None, :]
+        @pl.when(refill)
+        def _():
+            copy = pltpu.make_async_copy(ind_hbm.at[pl.ds(line, cap)], win, sem)
+            copy.start()
+            copy.wait()
+
+        lo = jnp.where(refill, line, lo)
+        hi = jnp.where(refill, lo + cap, hi)
+
+        def scan(st):
+            k, hit = st
+            u = win[k - lo]
+            ok = (u >= 0) & (u < n_bits)
+            word = mask_ref[0, jnp.clip(u, 0, n_bits - 1) // 32]
+            return k + 1, ok & (((word >> (u % 32)) & 1) != 0)
+
+        _, hit = lax.while_loop(
+            lambda st: (st[0] < e) & jnp.logical_not(st[1]), scan, (s, False)
+        )
+        return jnp.where(row_iota == j, hit.astype(jnp.int32), acc), lo, hi
+
+    acc, _, _ = lax.fori_loop(
+        0, tr, row, (jnp.zeros((1, tr), jnp.int32), jnp.int32(0), jnp.int32(0))
+    )
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("deg_cap", "interpret", "row_tile"))
@@ -168,68 +194,73 @@ def csr_arc_sweep(
     indices: jnp.ndarray,  # [n_idx] int32 flat CSR columns (sentinel tail)
     arc_row: jnp.ndarray,  # [n_arcs] int32 plane index per arc
     masks: jnp.ndarray,  # [n_arcs, w] uint32 (D(q) bitmap per arc)
-    deg_cap: int = 8,
-    interpret: bool = True,
+    *,
+    deg_cap: int,
+    interpret: bool,
     row_tile: int = ROW_TILE,
 ) -> jnp.ndarray:
     """All arcs of one CSR AC sweep in one kernel call (DESIGN.md §11).
 
     ``out[a, t] = any(u in row(arc_row[a], t) : bit u set in masks[a])`` —
-    ``[n_arcs, n_t]`` int32 {0, 1}, the sparse twin of `arc_any_sweep`.
-    Grid ``(n_arcs, row tiles)``; the per-plane ``seg_start`` / ``seg_len``
-    blocks are selected by the scalar-prefetched ``arc_row`` table, and
-    each row's neighbor segment is a ``pl.ds`` slice of the flat VMEM
-    ``indices`` block — dense adjacency bitmaps never exist.  ``indices``
-    must be over-padded by ``deg_cap``
-    (`repro.core.domains.csr_target_domain_arrays` guarantees it) so
-    segment slices never clamp.  Oracle:
-    `repro.kernels.ref.csr_arc_sweep_ref`.
+    ``[n_arcs, n_t]`` int32 {0, 1}, the sparse twin of `arc_any_sweep`;
+    each row is consumed for at most ``deg_cap`` entries.  Grid ``(n_arcs,
+    row tiles)``; the per-plane ``seg_start`` / ``seg_len`` tiles and the
+    arc's mask are SMEM blocks selected by the scalar-prefetched
+    ``arc_row`` table, and ``indices`` stays in HBM, windowed into SMEM —
+    dense adjacency bitmaps never exist.  Rows of one plane that are
+    consecutive in ``indices`` (as CSR lays them out) share a window.
+    Oracle: `repro.kernels.ref.csr_arc_sweep_ref`.
     """
     n_arcs, w = masks.shape
-    n_t = seg_start.shape[1]
+    n_planes, n_t = seg_start.shape
     wp = pad_words(w)
     tr = min(row_tile, max(8, ((n_t + 7) // 8) * 8))
     n_pad = ((n_t + tr - 1) // tr) * tr
+    n_tiles = n_pad // tr
     sst_p = jnp.pad(seg_start, ((0, 0), (0, n_pad - n_t)))
     sln_p = jnp.pad(seg_len, ((0, 0), (0, n_pad - n_t)))  # pad rows: len 0
     masks_p = jnp.pad(masks, ((0, 0), (0, wp - w)))
-    n_ind = indices.shape[0]
-    n_ipad = pad_words(n_ind)
-    if n_ipad != n_ind:
-        indices = jnp.pad(indices, (0, n_ipad - n_ind), constant_values=SENTINEL)
+    # the window covers any one row (deg_cap words plus its offset into its
+    # first 1024-word line); the sentinel tail keeps every refill in bounds
+    cap = max(8 * DMA_WORDS, -(-(deg_cap + DMA_WORDS) // DMA_WORDS) * DMA_WORDS)
+    n_ind = -(-indices.shape[0] // DMA_WORDS) * DMA_WORDS + cap
+    indices = jnp.pad(indices, (0, n_ind - indices.shape[0]),
+                      constant_values=SENTINEL)
 
     def seg_map(a, i, arc_row_s):
-        return (arc_row_s[a], i)
-
-    def ind_map(a, i, arc_row_s):
-        return (0, 0)
+        return (arc_row_s[a], i, 0, 0)
 
     def mask_map(a, i, arc_row_s):
-        return (a, 0)
+        return (a, 0, 0)
 
     def out_map(a, i, arc_row_s):
-        return (a, i)
+        return (a, 0, i)
 
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         functools.partial(_csr_sweep_kernel, deg_cap=deg_cap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_arcs, n_pad // tr),
+            grid=(n_arcs, n_tiles),
             in_specs=[
-                pl.BlockSpec((1, tr), seg_map),  # seg_start
-                pl.BlockSpec((1, tr), seg_map),  # seg_len
-                pl.BlockSpec((1, n_ipad), ind_map),  # flat CSR indices
-                pl.BlockSpec((1, wp), mask_map),
+                smem((None, None, 1, tr), seg_map),  # seg_start tile
+                smem((None, None, 1, tr), seg_map),  # seg_len tile
+                smem((None, 1, wp), mask_map),  # the arc's D(q) bitmap
+                pl.BlockSpec(memory_space=pltpu.HBM),  # flat CSR indices
             ],
-            out_specs=pl.BlockSpec((1, tr), out_map),
+            out_specs=pl.BlockSpec((None, 1, tr), out_map),
+            scratch_shapes=[
+                pltpu.SMEM((cap,), jnp.int32),
+                pltpu.SemaphoreType.DMA(()),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_arcs, n_pad), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n_arcs, 1, n_pad), jnp.int32),
         interpret=interpret,
     )(
         arc_row.astype(jnp.int32),
-        sst_p.astype(jnp.int32),
-        sln_p.astype(jnp.int32),
-        indices.reshape(1, n_ipad),
-        masks_p,
+        sst_p.astype(jnp.int32).reshape(n_planes, n_tiles, 1, tr),
+        sln_p.astype(jnp.int32).reshape(n_planes, n_tiles, 1, tr),
+        lax.bitcast_convert_type(masks_p, jnp.int32)[:, None, :],
+        indices,
     )
-    return out[:, :n_t]
+    return out[:, 0, :n_t]

@@ -65,18 +65,19 @@ def _lowest_bit(c: jnp.ndarray):
 
     Returns ``(valid, v, vmask)``: a scalar flag, the global bit index
     (garbage when ``!valid`` — callers gate on ``valid``), and the one-hot
-    ``[1, wp]`` mask of the bit (all-zero when ``!valid``).
+    ``[1, wp]`` mask of the bit (all-zero when ``!valid``).  Every
+    reduction runs over int32 words and the popcount stays a vector op:
+    the TPU lowering has neither unsigned reductions nor scalar popcounts.
     """
-    nz = c != jnp.uint32(0)
-    valid = jnp.any(nz)
+    wp = c.shape[1]
     iota = lax.broadcasted_iota(jnp.int32, c.shape, 1)
-    widx = jnp.min(jnp.where(nz, iota, c.shape[1]))  # first non-zero word
+    widx = jnp.min(jnp.where(c != jnp.uint32(0), iota, wp))  # first non-zero word
+    valid = widx < wp
     sel = iota == widx
-    word = jnp.sum(jnp.where(sel, c, jnp.uint32(0)), dtype=jnp.uint32)
-    tz = lax.population_count(~word & (word - jnp.uint32(1)))
-    v = widx * WORD_BITS + tz.astype(jnp.int32)
-    lowbit = word & (~word + jnp.uint32(1))
-    vmask = jnp.where(sel, lowbit, jnp.uint32(0))
+    tz_words = lax.population_count(~c & (c - jnp.uint32(1))).astype(jnp.int32)
+    tz = jnp.sum(jnp.where(sel, tz_words, 0))
+    v = widx * WORD_BITS + tz
+    vmask = jnp.where(sel, c & (~c + jnp.uint32(1)), jnp.uint32(0))
     return valid, v, vmask
 
 
@@ -131,15 +132,15 @@ def extend_step(
     n_p: jnp.ndarray,  # scalar int32 actual pattern size
     used: jnp.ndarray,  # [b, w] uint32
     cand: jnp.ndarray,  # [b, w] uint32
-    interpret: bool = True,
+    interpret: bool,
 ):
     """One fused expansion over ``b`` lanes.
 
     Returns ``(cand2 [b, w], child_cand [b, w], meta [b, 4] int32)`` with
     ``meta`` columns ``(valid, v, is_match, has_child)``; ``v`` is -1 on
-    invalid lanes.  ``interpret=True`` executes the kernel body in Python
-    on CPU (the validation mode for this container); on TPU the wrapper in
-    `repro.kernels.ops` auto-selects compiled mode.
+    invalid lanes.  ``interpret`` comes from
+    `repro.kernels.ops.resolve_interpret`: compiled on a TPU, the Pallas
+    interpreter anywhere else.
     """
     b, w = cand.shape
     mp = row_idx.shape[1]
@@ -157,49 +158,54 @@ def extend_step(
 
     grid = (b, mp + 2)
 
+    # Every bitmap is viewed as [rows, 1, wp] and blocked (squeezed, 1, wp):
+    # a (1, wp) block of a [b, wp] array breaks the TPU rule that a block's
+    # last two dims tile by (8, 128) or span the array.  The row table is
+    # flattened to 1-D, since SMEM pads a 2-D table's minor dim to 128.
     def lane_map(l, j, cpos_s, ridx_s, depth_s, np_s):
-        return (l, 0)
+        return (l, 0, 0)
 
     def dom_map(l, j, cpos_s, ridx_s, depth_s, np_s):
-        return (cpos_s[l], 0)
+        return (cpos_s[l], 0, 0)
 
     def row_map(l, j, cpos_s, ridx_s, depth_s, np_s):
         # j == 0 init and j == mp + 1 finalize get the neutral row
         jj = jnp.clip(j - 1, 0, mp - 1)
         take = (j >= 1) & (j <= mp)
-        return (jnp.where(take, ridx_s[l, jj], n_rows), 0)
+        return (jnp.where(take, ridx_s[l * mp + jj], n_rows), 0, 0)
 
+    bitmap = functools.partial(pl.BlockSpec, (None, 1, wp))
     cand2, child, meta = pl.pallas_call(
         functools.partial(_kernel, mp=mp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, wp), lane_map),  # cand
-                pl.BlockSpec((1, wp), lane_map),  # used
-                pl.BlockSpec((1, wp), dom_map),  # dom_bits
-                pl.BlockSpec((1, wp), row_map),  # adjacency rows
+                bitmap(lane_map),  # cand
+                bitmap(lane_map),  # used
+                bitmap(dom_map),  # dom_bits
+                bitmap(row_map),  # adjacency rows
             ],
             out_specs=[
-                pl.BlockSpec((1, wp), lane_map),  # cand2
-                pl.BlockSpec((1, wp), lane_map),  # child_cand
-                pl.BlockSpec((1, META_WIDTH), lane_map),  # meta
+                bitmap(lane_map),  # cand2
+                bitmap(lane_map),  # child_cand
+                pl.BlockSpec((None, 1, META_WIDTH), lane_map),  # meta
             ],
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, wp), jnp.uint32),
-            jax.ShapeDtypeStruct((b, wp), jnp.uint32),
-            jax.ShapeDtypeStruct((b, META_WIDTH), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, wp), jnp.uint32),
+            jax.ShapeDtypeStruct((b, 1, wp), jnp.uint32),
+            jax.ShapeDtypeStruct((b, 1, META_WIDTH), jnp.int32),
         ),
         interpret=interpret,
     )(
         child_pos.astype(jnp.int32),
-        row_idx.astype(jnp.int32),
+        row_idx.astype(jnp.int32).reshape(b * mp),
         depth.astype(jnp.int32),
         jnp.asarray(n_p, jnp.int32).reshape((1,)),
-        cand,
-        used,
-        dom_bits,
-        rows,
+        cand[:, None, :],
+        used[:, None, :],
+        dom_bits[:, None, :],
+        rows[:, None, :],
     )
-    return cand2[:, :w], child[:, :w], meta
+    return cand2[:, 0, :w], child[:, 0, :w], meta[:, 0, :]

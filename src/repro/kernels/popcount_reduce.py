@@ -30,7 +30,8 @@ def _kernel(bits_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret", "row_tile"))
 def popcount_rows(
     bits: jnp.ndarray,  # [n, w] uint32
-    interpret: bool = True,
+    *,
+    interpret: bool,
     row_tile: int = ROW_TILE,
 ) -> jnp.ndarray:
     n, w = bits.shape

@@ -30,6 +30,7 @@ from typing import List, Optional
 from repro.core import EngineConfig, Enumerator, Query, SubgraphIndex
 from repro.core.plan import build_csr_plan
 from repro.data import graphgen
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import EnumerationService, ServiceConfig, format_snapshot
 
 
@@ -110,6 +111,7 @@ def verify(results: List[tuple], svc: EnumerationService, n_check: int) -> None:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small corpus + tight timeouts (CI)")

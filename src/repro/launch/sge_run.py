@@ -74,9 +74,11 @@ import jax  # noqa: E402  (after the XLA_FLAGS shim, deliberately)
 
 from repro.core import EngineConfig, Enumerator, SubgraphIndex  # noqa: E402
 from repro.data import graphgen  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--collection", default="ppis32-like",
                     choices=sorted(graphgen.COLLECTIONS))

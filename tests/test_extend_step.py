@@ -122,20 +122,108 @@ def test_unknown_step_backend_rejected():
         EngineConfig(step_backend="bogus")
 
 
-def test_resolve_interpret_env_override(monkeypatch):
-    monkeypatch.delenv("SGE_PALLAS_INTERPRET", raising=False)
-    default = ops.resolve_interpret(None)
-    assert default == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("SGE_PALLAS_INTERPRET", "0")
-    assert ops.resolve_interpret(None) is False
-    monkeypatch.setenv("SGE_PALLAS_INTERPRET", "1")
-    assert ops.resolve_interpret(None) is True
-    # set-but-empty (the `VAR= cmd` clearing idiom) falls back to autodetect
-    monkeypatch.setenv("SGE_PALLAS_INTERPRET", "")
-    assert ops.resolve_interpret(None) == default
-    # explicit argument beats the env
-    assert ops.resolve_interpret(False) is False
-    assert ops.resolve_interpret(True) is True
+@pytest.mark.parametrize("v,w", [(0, 1), (31, 2), (32, 2), (33066, 1034), (-1, 3), (96, 3)])
+def test_bit_row_one_hot(v, w):
+    """Bit ``v`` set and nothing else; out-of-range ``v`` sets nothing."""
+    got = np.asarray(jax.vmap(extend.bit_row, (0, None))(jnp.asarray([v]), w))[0]
+    want = np.zeros(w, np.uint32)
+    if 0 <= v < 32 * w:
+        want[v // 32] = np.uint32(1) << np.uint32(v % 32)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_resolve_interpret_follows_backend():
+    """Interpret mode iff the backend is not a TPU; nothing overrides it."""
+    assert ops.resolve_interpret() is (jax.default_backend() != "tpu")
+
+
+@pytest.mark.parametrize("kernel", [
+    "extend_step.extend_step", "csr_extend.csr_extend",
+    "csr_extend.csr_extend_bucketed", "domain_ac.adjacency_any",
+    "domain_ac.arc_any_sweep", "domain_ac.csr_arc_sweep",
+    "popcount_reduce.popcount_rows", "candidate_mask.candidate_mask",
+])
+def test_kernels_take_interpret_explicitly(kernel):
+    """A direct kernel call must say how it runs: no kernel defaults to
+    interpret mode, so a caller on the chip cannot interpret by accident."""
+    import importlib
+    import inspect
+
+    mod, name = kernel.split(".")
+    fn = getattr(importlib.import_module(f"repro.kernels.{mod}"), name)
+    param = inspect.signature(fn).parameters["interpret"]
+    assert param.default is inspect.Parameter.empty
+
+
+def _csr_target(rng, n_t, deg_cap, hubs, n_planes=2, avg=6):
+    """Sorted CSR planes of a random target with ``hubs`` rows at
+    ``deg_cap`` per plane; returns (indptr, sentinel-padded indices)."""
+    degs = np.minimum(rng.poisson(avg, (n_planes, n_t)), deg_cap)
+    degs[:, :hubs] = deg_cap
+    indptr = np.zeros((n_planes, n_t + 1), np.int64)
+    indptr[:, 1:] = np.cumsum(degs, axis=1)
+    indptr += np.concatenate([[0], np.cumsum(degs.sum(axis=1))[:-1]])[:, None]
+    rows = [np.sort(rng.choice(n_t, int(d), replace=False))
+            for d in degs.reshape(-1)]
+    nnz = int(degs.sum())
+    indices = np.full(((nnz + 1023) // 1024) * 1024 + deg_cap, 2**31 - 1,
+                      np.int32)
+    indices[:nnz] = np.concatenate(rows)
+    return indptr, indices
+
+
+@pytest.mark.parametrize("walk", ["flat", "bucketed"])
+@pytest.mark.parametrize("n_t,b,mp,deg_cap,hubs", [
+    (300, 16, 3, 16, 0),
+    (1500, 12, 4, 1500, 3),  # hub segments span several 1024-word DMAs
+])
+def test_csr_extend_kernels_vs_oracle(rng, walk, n_t, b, mp, deg_cap, hubs):
+    """Both CSR walk kernels against their oracles, with unused parent
+    slots, empty candidate sets, completed matches and hub rows."""
+    w = (n_t + 31) // 32
+    indptr, indices = _csr_target(rng, n_t, deg_cap, hubs)
+    p_pad = 8
+    plane = rng.integers(0, 2, (b, mp))
+    t = rng.integers(0, n_t, (b, mp))
+    t[:, 0] = rng.integers(0, max(hubs, 1), b)
+    seg_start = indptr[plane, t].astype(np.int32)
+    seg_len = (indptr[plane, t + 1] - indptr[plane, t]).astype(np.int32)
+    seg_len[rng.random((b, mp)) < 0.25] = -1
+    dom = rng.integers(0, 2**32, (p_pad, w), dtype=np.uint32)
+    used = (rng.integers(0, 2**32, (b, w), dtype=np.uint32)
+            & rng.integers(0, 2**32, (b, w), dtype=np.uint32))
+    cand = rng.integers(0, 2**32, (b, w), dtype=np.uint32)
+    cand[::4] = 0
+    args = [jnp.asarray(x) for x in (
+        indices, dom, seg_start, seg_len,
+        rng.integers(0, p_pad, b).astype(np.int32),
+        rng.integers(0, 5, b).astype(np.int32), np.int32(4), used, cand)]
+    if walk == "flat":
+        got = ops.csr_extend(*args, deg_cap=deg_cap)
+        want = kref.csr_extend_ref(*args, deg_cap=deg_cap)
+    else:
+        got = ops.csr_extend_bucketed(*args, deg_cap=deg_cap)
+        want = kref.csr_extend_bucketed_ref(*args, deg_cap=deg_cap)
+    for g, wnt, name in zip(got, want, ("cand2", "child_cand", "meta")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt), err_msg=name)
+
+
+def test_csr_arc_sweep_refills_its_window(rng):
+    """Planes longer than the kernel's SMEM window (8,192 words) are swept
+    in several refills, hub rows included; flags equal the oracle's."""
+    n_t, deg_cap = 2000, 1200
+    w = (n_t + 31) // 32
+    indptr, indices = _csr_target(rng, n_t, deg_cap, hubs=4)
+    masks = (rng.integers(0, 2**32, (5, w), dtype=np.uint32)
+             & rng.integers(0, 2**32, (5, w), dtype=np.uint32)
+             & rng.integers(0, 2**32, (5, w), dtype=np.uint32))
+    args = [jnp.asarray(x) for x in (
+        indptr[:, :-1].astype(np.int32), np.diff(indptr, axis=1).astype(np.int32),
+        indices, np.array([0, 1, 1, 0, 1], np.int32), masks)]
+    np.testing.assert_array_equal(
+        np.asarray(ops.csr_arc_sweep(*args, deg_cap=deg_cap)),
+        np.asarray(kref.csr_arc_sweep_ref(*args, deg_cap=deg_cap)),
+    )
 
 
 # ---------------------------------------------------------------------------

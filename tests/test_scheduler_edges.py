@@ -137,8 +137,6 @@ def test_steal_round_conserves_entries():
 def test_sharded_steal_round_matches_unsharded_on_one_device():
     """The shard_map round with D=1 (collectives are identities) must be
     state-for-state identical to the plain round."""
-    from jax.experimental.shard_map import shard_map
-
     cfg = EngineConfig(n_workers=4, expand_width=2,
                        steal_chunk=3, keep_min=1, recv_cap=2)
     state = _toy_state([6, 0, 5, 0], cfg)
@@ -146,9 +144,9 @@ def test_sharded_steal_round_matches_unsharded_on_one_device():
 
     mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1])
     specs = eng.state_partition_specs("data")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(eng._steal_round_sharded, cfg, axis="data"),
-        mesh=mesh, in_specs=(specs,), out_specs=specs, check_rep=False,
+        mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False,
     )
     out = jax.jit(fn)(state)
     for name in EngineState._fields:
