@@ -812,6 +812,7 @@ def device_fixpoint(
     )
     if batched:
         fn = jax.vmap(fn, in_axes=(None, 0))
+    fn.__name__ = "domain_fixpoint"  # the device program jit_domain_fixpoint
     return jax.jit(fn)
 
 

@@ -30,6 +30,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import domains as dom_mod
 from repro.core import ordering as ord_mod
 from repro.core.graph import (
@@ -149,12 +150,6 @@ def build_plan(
     flags = variant_flags(variant)
     use_ds, use_si = flags["use_ac"], flags["use_si"]
 
-    seed = _resolve_seed_edge(
-        pattern, seed_edge,
-        csr_factory if csr_factory is not None
-        else (lambda: csr_planes_from_bitmaps(target.adj_bits)),
-    )
-
     # --- domains ---------------------------------------------------------
     if domains is not None:
         if domains.bits.shape != (pattern.n, target.w):
@@ -164,15 +159,22 @@ def build_plan(
             )
         dres = domains
     else:
-        dres = dom_mod.compute_domains(
-            pattern, target, use_ac=use_ds, use_fc=flags["use_fc"],
-            ac_iters=ac_iters, interleave=flags["interleave"],
+        with trace.span("prepare.domains"):
+            dres = dom_mod.compute_domains(
+                pattern, target, use_ac=use_ds, use_fc=flags["use_fc"],
+                ac_iters=ac_iters, interleave=flags["interleave"],
+            )
+    with trace.span("prepare.plan"):
+        seed = _resolve_seed_edge(
+            pattern, seed_edge,
+            csr_factory if csr_factory is not None
+            else (lambda: csr_planes_from_bitmaps(target.adj_bits)),
         )
-    return _assemble_plan(
-        pattern, dres, variant, use_ds, use_si, p_pad, max_parents,
-        n_t=target.n, w=target.w, adj_bits=target.adj_bits, csr=None,
-        anchor=anchor, csr_factory=csr_factory, seed_edge=seed,
-    )
+        return _assemble_plan(
+            pattern, dres, variant, use_ds, use_si, p_pad, max_parents,
+            n_t=target.n, w=target.w, adj_bits=target.adj_bits, csr=None,
+            anchor=anchor, csr_factory=csr_factory, seed_edge=seed,
+        )
 
 
 def build_csr_plan(
@@ -223,24 +225,26 @@ def build_csr_plan(
             )
         dres = domains
     else:
-        tgt_arrays = (
-            dom_mod.csr_target_domain_arrays(target, w, planes=planes)
-            if (use_ds or flags["use_fc"]) else None
+        with trace.span("prepare.domains"):
+            tgt_arrays = (
+                dom_mod.csr_target_domain_arrays(target, w, planes=planes)
+                if (use_ds or flags["use_fc"]) else None
+            )
+            dres = dom_mod.compute_domains_sparse(
+                pattern, target, w, use_ac=use_ds, use_fc=flags["use_fc"],
+                interleave=flags["interleave"], use_pallas=use_pallas,
+                ac_iters=ac_iters, tgt_arrays=tgt_arrays,
+            )
+    with trace.span("prepare.plan"):
+        seed = _resolve_seed_edge(pattern, seed_edge, lambda: planes)
+        return _assemble_plan(
+            pattern, dres, variant, use_ds=use_ds, use_si=use_si,
+            p_pad=p_pad, max_parents=max_parents,
+            n_t=target.n, w=w,
+            adj_bits=np.zeros((n_elab, 2, 0, w), dtype=np.uint32),
+            csr=planes,
+            anchor=anchor, seed_edge=seed,
         )
-        dres = dom_mod.compute_domains_sparse(
-            pattern, target, w, use_ac=use_ds, use_fc=flags["use_fc"],
-            interleave=flags["interleave"], use_pallas=use_pallas,
-            ac_iters=ac_iters, tgt_arrays=tgt_arrays,
-        )
-    seed = _resolve_seed_edge(pattern, seed_edge, lambda: planes)
-    return _assemble_plan(
-        pattern, dres, variant, use_ds=use_ds, use_si=use_si,
-        p_pad=p_pad, max_parents=max_parents,
-        n_t=target.n, w=w,
-        adj_bits=np.zeros((n_elab, 2, 0, w), dtype=np.uint32),
-        csr=planes,
-        anchor=anchor, seed_edge=seed,
-    )
 
 
 def _resolve_seed_edge(pattern: Graph, seed_edge, planes_factory):

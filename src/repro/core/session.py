@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core import delta as delta_mod
 from repro.core import domains as dom_mod
 from repro.core import engine as eng
@@ -864,7 +865,9 @@ class Enumerator:
                         csr_only=eng.is_csr_only(query.plan),
                     )
                 else:
-                    fn = jax.jit(functools.partial(eng._engine_loop, cfg))
+                    loop = functools.partial(eng._engine_loop, cfg)
+                    loop.__name__ = "_engine_loop"  # device program jit__engine_loop
+                    fn = jax.jit(loop)
             else:
                 fn = jax.jit(jax.vmap(functools.partial(eng._engine_loop, cfg)))
             with self._cache_lock:
@@ -1629,33 +1632,46 @@ class Enumerator:
 
     def _run_pack(
         self, members: List[int], qs: List[Query], cfg: EngineConfig, pack_size: int
-    ) -> Iterator[MatchSet]:
-        """Execute one padded pack of same-bucket queries, yielding results."""
+    ) -> List[MatchSet]:
+        """Execute one padded pack of same-bucket queries."""
         t0 = time.perf_counter()
-        plans = [qs[i].plan for i in members]
-        fn = self._engine_fn(cfg, "batch", pack_size, qs[members[0]])
-        arrays = [eng.plan_arrays_for(cfg, p) for p in plans]
-        states = [eng.init_state(p, cfg) for p in plans]
-        # pad inert lanes so every pack of this bucket shares one compilation
-        # (size==0 lanes freeze immediately under the vmapped while_loop)
-        while len(arrays) < pack_size:
-            arrays.append(arrays[0])
-            states.append(_inert_state(states[0]))
-        stacked_plan = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
-        stacked_state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-        final = jax.block_until_ready(fn(stacked_plan, stacked_state))
+        with trace.span("pack.build"):
+            plans = [qs[i].plan for i in members]
+            fn = self._engine_fn(cfg, "batch", pack_size, qs[members[0]])
+            arrays = [eng.plan_arrays_for(cfg, p) for p in plans]
+            states = [eng.init_state(p, cfg) for p in plans]
+            # pad inert lanes so every pack of this bucket shares one
+            # compilation (size==0 lanes freeze immediately under the
+            # vmapped while_loop)
+            while len(arrays) < pack_size:
+                arrays.append(arrays[0])
+                states.append(_inert_state(states[0]))
+            stacked_plan = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
+            stacked_state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        with trace.span("pack.device") as sp:
+            final = jax.block_until_ready(fn(stacked_plan, stacked_state))
+            if sp is not None:
+                # loop rounds each lane ran; the vmapped loop runs until
+                # its slowest lane stops
+                steps = np.asarray(final.steps)[: len(members)]
+                sp.add(occupied=len(members), steps_max=int(steps.max()),
+                       steps_sum=int(steps.sum()))
         match_s = (time.perf_counter() - t0) / max(len(members), 1)
-        for row, i in enumerate(members):
-            lane = jax.tree.map(lambda x, r=row: x[r], final)
-            res = eng.result_from_state(lane, cfg)
-            if res.overflow:
-                # the pack undercounted this lane; go straight to the
-                # doubled-stack_cap single retry (re-running at the original
-                # cap would deterministically overflow again)
-                res = self._retry_overflowed(cfg, qs[i])
-                yield self._matchset(qs[i], i, res, match_s, retries=1)
-                continue
-            yield self._matchset(qs[i], i, res, match_s)
+        out = []
+        with trace.span("pack.decode"):
+            for row, i in enumerate(members):
+                lane = jax.tree.map(lambda x, r=row: x[r], final)
+                res = eng.result_from_state(lane, cfg)
+                if res.overflow:
+                    # the pack undercounted this lane; go straight to the
+                    # doubled-stack_cap single retry (re-running at the
+                    # original cap would deterministically overflow again)
+                    with trace.span("pack.retry"):
+                        res = self._retry_overflowed(cfg, qs[i])
+                    out.append(self._matchset(qs[i], i, res, match_s, retries=1))
+                else:
+                    out.append(self._matchset(qs[i], i, res, match_s))
+        return out
 
     # -- result assembly ---------------------------------------------------
 

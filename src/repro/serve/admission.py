@@ -47,6 +47,7 @@ class Request:
     collect: int                  # per-worker match-materialization budget
     submitted_at: float
     seq: int = 0                  # admission order (diagnostics)
+    popped_at: Optional[float] = None  # the dispatcher took it off the queue
 
 
 class AdmissionQueue:

@@ -43,9 +43,6 @@ class LatencyWindow:
         rank = max(0, min(len(ordered) - 1, int(round(p / 100.0 * len(ordered))) - 1))
         return ordered[rank]
 
-    def mean(self) -> float:
-        return sum(self._buf) / len(self._buf) if self._buf else 0.0
-
     def max(self) -> float:
         return max(self._buf) if self._buf else 0.0
 
@@ -78,11 +75,10 @@ class ServiceMetrics:
         self._lock = threading.Lock()
         self._counters: Dict[str, int] = {name: 0 for name in COUNTERS}
         self._latency = LatencyWindow(window)       # submit -> terminal
-        self._queue_wait = LatencyWindow(window)    # submit -> dispatch
+        self._queue_wait = LatencyWindow(window)    # submit -> its pack starts
         self._completion_times: collections.deque = collections.deque(maxlen=window)
         self._lanes_occupied = 0
         self._lanes_total = 0
-        self._started_at = clock()
 
     # -- observation (any thread) -----------------------------------------
 
@@ -99,6 +95,8 @@ class ServiceMetrics:
             self._lanes_total += lanes
 
     def observe_queue_wait(self, wait_s: float) -> None:
+        """A request waited ``wait_s`` from submit to the start of the pack
+        that carried it: admission queue and coalescer together."""
         with self._lock:
             self._queue_wait.record(wait_s)
 
@@ -129,13 +127,11 @@ class ServiceMetrics:
         service at call time (they are gauges, not counters)."""
         with self._lock:
             out: Dict[str, float] = dict(self._counters)
-            out["uptime_s"] = self._clock() - self._started_at
             out["queue_depth"] = queue_depth
             out["coalescing"] = coalescing
             out["in_flight"] = in_flight
             out["latency_p50_s"] = self._latency.percentile(50)
             out["latency_p99_s"] = self._latency.percentile(99)
-            out["latency_mean_s"] = self._latency.mean()
             out["latency_max_s"] = self._latency.max()
             out["queue_wait_p50_s"] = self._queue_wait.percentile(50)
             out["queue_wait_p99_s"] = self._queue_wait.percentile(99)
@@ -167,6 +163,8 @@ def format_snapshot(stats: Dict[str, float]) -> str:
         "chunks={chunks:.0f}",
         "latency   p50={latency_p50_s:.4f}s p99={latency_p99_s:.4f}s "
         "max={latency_max_s:.4f}s qps={qps:.1f}",
+        "queue     wait_p50={queue_wait_p50_s:.4f}s "
+        "wait_p99={queue_wait_p99_s:.4f}s",
     ]
     if "cache_compiles" in stats:
         lines.append(

@@ -23,6 +23,8 @@ the same continuous-batching shape production inference stacks use:
   status.  Results stream back per client as chunked match-mapping
   slices (`repro.serve.stream`), and `repro.serve.metrics` records QPS,
   queue depth, batch occupancy, latency percentiles, and cache hit rate.
+  With `repro.trace` on, the dispatcher also records each request's
+  admission and coalescer waits and a span per wait, pack and delivery.
 
 All JAX dispatch happens on the dispatcher thread; client threads only
 touch numpy (query preparation) and thread-safe queues.  One dispatcher
@@ -35,8 +37,9 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Dict, Optional, Union
+from typing import Dict, List, Optional, Union
 
+from repro import trace
 from repro.core.engine import EngineConfig
 from repro.core.graph import Graph, PackedGraph
 from repro.core.session import Enumerator, Query, SubgraphIndex
@@ -113,7 +116,7 @@ class EnumerationService:
         config: Optional[EngineConfig] = None,
         service: Optional[ServiceConfig] = None,
         enumerator: Optional[Enumerator] = None,
-        clock=time.monotonic,
+        clock=time.perf_counter,
         **config_kwargs,
     ):
         self.service_config = service or ServiceConfig()
@@ -313,8 +316,9 @@ class EnumerationService:
                 timeout = min(idle_wait, max(deadline - self._clock(), 0.0))
             if self._stop.is_set():
                 timeout = 0.0
-            for req in self.admission.pop(timeout=timeout):
-                self.metrics.observe_queue_wait(self._clock() - req.submitted_at)
+            with trace.span("serve.wait"):
+                reqs = self._pop(timeout)
+            for req in reqs:
                 full = self.coalescer.add(self._bucket_key(req), req)
                 if full is not None:
                     self._execute(*full)
@@ -328,10 +332,17 @@ class EnumerationService:
                 if drained:
                     return
 
+    def _pop(self, timeout: float) -> List[Request]:
+        reqs = self.admission.pop(timeout=timeout)
+        now = self._clock()
+        for req in reqs:
+            req.popped_at = now
+        return reqs
+
     def _settle_pending(self, drain: bool) -> None:
         """Resolve everything still queued/coalescing — executed (drain)
         or failed with a shutdown status — so no client blocks forever."""
-        batches = [(self._bucket_key(r), [r]) for r in self.admission.pop(timeout=0)]
+        batches = [(self._bucket_key(r), [r]) for r in self._pop(0)]
         batches += self.coalescer.flush()
         for key, batch in batches:
             if drain:
@@ -354,36 +365,52 @@ class EnumerationService:
         """Run one coalesced bucket as a single padded pack and deliver."""
         sc = self.service_config
         cfg = self._cfg_for(batch[0].collect)
+        start = self._clock()
+        for req in batch:
+            # submit -> pop (admission) and pop -> this pack (coalescer)
+            trace.record("serve.admission_wait", req.submitted_at,
+                         req.popped_at, req.stream.name)
+            trace.record("serve.coalesce_wait", req.popped_at, start,
+                         req.stream.name)
+            self.metrics.observe_queue_wait(start - req.submitted_at)
         self._in_flight = len(batch)
         try:
-            try:
-                results = self.enumerator.run_pack(
-                    [r.query for r in batch], pack_size=sc.max_lanes, cfg=cfg,
-                )
-            except Exception as e:  # noqa: BLE001 — server must not die
-                for req in batch:
-                    self._fail(req, f"{type(e).__name__}: {e}")
-                return
-            self.metrics.observe_dispatch(len(batch), sc.max_lanes)
-            for req, ms in zip(batch, results):
-                n_chunks = 0
-                if req.collect:
-                    maps = ms.mappings()  # decodes the pack's match buffer
-                    for start in range(0, len(maps), sc.chunk_size):
-                        part = maps[start:start + sc.chunk_size]
-                        req.stream._push_chunk(ResultChunk(
-                            seq=n_chunks,
-                            mappings=tuple(part),
-                            final=start + sc.chunk_size >= len(maps),
-                        ))
-                        n_chunks += 1
-                    self.metrics.inc("chunks", n_chunks)
-                latency = self._clock() - req.submitted_at
-                req.stream._finish(ResultStatus(
-                    ok=True, matchset=ms, error=None, retries=ms.retries,
-                    n_chunks=n_chunks, latency_s=latency,
-                ))
-                self.admission.release(req.tenant)
-                self.metrics.observe_completion(latency, retries=ms.retries)
+            with trace.span("serve.execute"):
+                try:
+                    results = self.enumerator.run_pack(
+                        [r.query for r in batch], pack_size=sc.max_lanes,
+                        cfg=cfg,
+                    )
+                except Exception as e:  # noqa: BLE001 — server must not die
+                    for req in batch:
+                        self._fail(req, f"{type(e).__name__}: {e}")
+                    return
+                self.metrics.observe_dispatch(len(batch), sc.max_lanes)
+                with trace.span("serve.deliver"):
+                    self._deliver(batch, results)
         finally:
             self._in_flight = 0
+
+    def _deliver(self, batch: list, results: list) -> None:
+        """Stream each request's mappings in chunks and finish it."""
+        sc = self.service_config
+        for req, ms in zip(batch, results):
+            n_chunks = 0
+            if req.collect:
+                maps = ms.mappings()  # decodes the pack's match buffer
+                for start in range(0, len(maps), sc.chunk_size):
+                    part = maps[start:start + sc.chunk_size]
+                    req.stream._push_chunk(ResultChunk(
+                        seq=n_chunks,
+                        mappings=tuple(part),
+                        final=start + sc.chunk_size >= len(maps),
+                    ))
+                    n_chunks += 1
+                self.metrics.inc("chunks", n_chunks)
+            latency = self._clock() - req.submitted_at
+            req.stream._finish(ResultStatus(
+                ok=True, matchset=ms, error=None, retries=ms.retries,
+                n_chunks=n_chunks, latency_s=latency,
+            ))
+            self.admission.release(req.tenant)
+            self.metrics.observe_completion(latency, retries=ms.retries)
