@@ -535,29 +535,81 @@ def run(plan: SearchPlan, cfg: EngineConfig, mesh: Optional[Mesh] = None) -> Eng
     return result_from_state(final, cfg)
 
 
-def result_from_state(final: EngineState, cfg: EngineConfig) -> EngineResult:
-    """Reduce a drained (unbatched) :class:`EngineState` to an
-    :class:`EngineResult` — shared by the one-shot :func:`run` and the
-    session executor (`repro.core.session`), whose batch path reduces one
-    vmapped lane at a time."""
-    steals = int(jnp.sum(final.steals))
-    sdepth = int(jnp.sum(final.steal_depth))
-    states = int(jnp.sum(final.states))
-    edepth = int(jnp.sum(final.exp_depth))
+class Counters(NamedTuple):
+    """What :class:`EngineResult` reads of a drained :class:`EngineState`,
+    reduced on the device: sums over workers, the per-worker arrays, loop
+    scalars and the match buffer (None unless collecting)."""
+
+    matches: jnp.ndarray  # [] summed over workers
+    states: jnp.ndarray  # []
+    steals: jnp.ndarray  # []
+    steal_depth: jnp.ndarray  # []
+    exp_depth: jnp.ndarray  # []
+    steps: jnp.ndarray  # []
+    steal_rounds: jnp.ndarray  # []
+    overflow: jnp.ndarray  # [] bool
+    per_worker_states: jnp.ndarray  # [V]
+    per_worker_matches: jnp.ndarray  # [V]
+    per_worker_steals: jnp.ndarray  # [V]
+    match_buf: Optional[jnp.ndarray]  # [V, Mcap, P]
+
+
+def reduce_state(final: EngineState, cfg: EngineConfig) -> Counters:
+    """Traceable: the :class:`Counters` of one drained (unbatched) state."""
+    return Counters(
+        matches=jnp.sum(final.matches),
+        states=jnp.sum(final.states),
+        steals=jnp.sum(final.steals),
+        steal_depth=jnp.sum(final.steal_depth),
+        exp_depth=jnp.sum(final.exp_depth),
+        steps=final.steps,
+        steal_rounds=final.steal_rounds,
+        overflow=final.overflow,
+        per_worker_states=final.states,
+        per_worker_matches=final.matches,
+        per_worker_steals=final.steals,
+        match_buf=final.match_buf if cfg.collect_matches else None,
+    )
+
+
+def result_from_counters(c: Counters) -> EngineResult:
+    """An :class:`EngineResult` from host :class:`Counters` of one run."""
+    steals, sdepth = int(c.steals), int(c.steal_depth)
+    states, edepth = int(c.states), int(c.exp_depth)
     return EngineResult(
-        matches=int(jnp.sum(final.matches)),
+        matches=int(c.matches),
         states=states,
-        steps=int(final.steps),
+        steps=int(c.steps),
         steals=steals,
-        steal_rounds=int(final.steal_rounds),
+        steal_rounds=int(c.steal_rounds),
         mean_steal_depth=(sdepth / steals) if steals else 0.0,
         mean_expand_depth=(edepth / states) if states else 0.0,
-        per_worker_states=np.asarray(final.states),
-        per_worker_matches=np.asarray(final.matches),
-        overflow=bool(final.overflow),
-        match_buf=np.asarray(final.match_buf) if cfg.collect_matches else None,
-        per_worker_steals=np.asarray(final.steals),
+        per_worker_states=np.asarray(c.per_worker_states),
+        per_worker_matches=np.asarray(c.per_worker_matches),
+        overflow=bool(c.overflow),
+        match_buf=None if c.match_buf is None else np.asarray(c.match_buf),
+        per_worker_steals=np.asarray(c.per_worker_steals),
     )
+
+
+def result_from_state(final: EngineState, cfg: EngineConfig) -> EngineResult:
+    """Reduce a drained (unbatched) :class:`EngineState` to an
+    :class:`EngineResult` with one transfer to the host."""
+    return result_from_counters(jax.device_get(reduce_state(final, cfg)))
+
+
+def make_pack_engine_fn(cfg: EngineConfig, p_pad: int):
+    """Jitted ``(stacked plan arrays, stacked Seeds) -> stacked Counters``:
+    a pack of same-shape queries, one per vmapped lane, seeded, run and
+    reduced in one device program, so the rings never exist on the host
+    (:func:`repro.core.frontier.stack_seeds` builds the seeds)."""
+
+    def lane(plan: extend.AnyPlanArrays, seeds: frontier.Seeds) -> Counters:
+        state = frontier.state_from_seeds(cfg, p_pad, seeds)
+        return reduce_state(_engine_loop(cfg, plan, state), cfg)
+
+    lane.__name__ = "_engine_loop"  # device program jit__engine_loop
+    return jax.jit(jax.vmap(lane))
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ paths (DESIGN.md §2.4).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple, TYPE_CHECKING
+from typing import NamedTuple, Optional, Sequence, Tuple, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
@@ -232,7 +232,28 @@ def overflowed(size: jnp.ndarray, s_cap: int) -> jnp.ndarray:
 # state construction / sharding metadata
 # ---------------------------------------------------------------------------
 
-def init_state(plan: SearchPlan, cfg: "EngineConfig") -> EngineState:
+class Seeds(NamedTuple):
+    """The entries a run starts from, before any ring exists: the host's
+    whole share of a run's initial state (:func:`state_from_seeds` builds
+    the rings around them on the device).
+
+    Worker ``v`` holds its first ``size[v]`` rows at ring slots ``0, 1,
+    ...``; rows past ``size`` are padding (empty bitmap, nothing mapped).
+    Dealt seeds (edge and delta seeding, :func:`deal_seeds`) carry their
+    depth and mapping.  Vertex seeding carries only each worker's depth-0
+    root bitmap (``R == 1``) and leaves ``depth``, ``map`` and ``size``
+    None: depth 0, nothing mapped, and a worker holds its root iff the
+    bitmap is not empty.  A pack stacks one :class:`Seeds` per lane on a
+    leading axis (:func:`stack_seeds`).
+    """
+
+    cand: np.ndarray  # [V, R, W] uint32
+    depth: Optional[np.ndarray] = None  # [V, R] int32
+    map: Optional[np.ndarray] = None  # [V, R, P] int32
+    size: Optional[np.ndarray] = None  # [V] int32
+
+
+def seed_rows(plan: SearchPlan, cfg: "EngineConfig") -> Seeds:
     """Initial work distribution, dispatched on ``cfg.root_seeding``
     (DESIGN.md §10).
 
@@ -264,12 +285,18 @@ def init_state(plan: SearchPlan, cfg: "EngineConfig") -> EngineState:
         k = int(sd.shape[0])
         per_worker = -(-k // v) if k else 0
         if per_worker <= s_cap - 1:
-            return init_delta_state(plan, cfg, sd, sm, sc)
+            return deal_seeds(plan, cfg, sd, sm, sc)
         mask = bitmap_from_indices(
             sm[:, 0].astype(np.int64), plan.n_t, plan.w
         )
-        return _init_vertex_state(plan, cfg, root_mask=mask)
-    return _init_vertex_state(plan, cfg)
+        return _vertex_seeds(plan, cfg, root_mask=mask)
+    return _vertex_seeds(plan, cfg)
+
+
+def init_state(plan: SearchPlan, cfg: "EngineConfig") -> EngineState:
+    """The initial :class:`EngineState` of a run: :func:`seed_rows` on the
+    host, the rings around them on the device."""
+    return seeded_state(cfg, plan.p_pad, seed_rows(plan, cfg))
 
 
 def root_seed_entries(plan: SearchPlan):
@@ -328,16 +355,12 @@ def root_seed_entries(plan: SearchPlan):
     )
 
 
-def _init_vertex_state(
+def _vertex_seeds(
     plan: SearchPlan, cfg: "EngineConfig", root_mask: Optional[np.ndarray] = None
-) -> EngineState:
+) -> Seeds:
     """The classic depth-0 root split; ``root_mask`` optionally restricts
     the root candidates (edge seeding's capacity fallback)."""
-    v = cfg.n_workers
-    p_pad, w = plan.p_pad, plan.w
-    s_cap = cfg.resolved_stack_cap(p_pad)
-    mcap = max(1, cfg.collect_matches)
-
+    v, w = cfg.n_workers, plan.w
     splits = np.linspace(0, plan.n_t, v + 1).astype(np.int64)
     root_cands = np.zeros((v, w), dtype=np.uint32)
     for kk in range(v):
@@ -348,31 +371,46 @@ def _init_vertex_state(
         root_cands &= root_mask[None, :]
     if not plan.satisfiable:
         root_cands[:] = 0
+    return Seeds(cand=root_cands[:, None, :])
 
-    st_depth = np.zeros((v, s_cap), dtype=np.int32)
-    st_map = np.full((v, s_cap, p_pad), -1, dtype=np.int32)
-    st_used = np.zeros((v, s_cap, w if cfg.store_used else 1), dtype=np.uint32)
-    st_cand = np.zeros((v, s_cap, w), dtype=np.uint32)
-    st_cand[:, 0] = root_cands
-    size = (root_cands.any(axis=1)).astype(np.int32)
 
-    return EngineState(
-        st_depth=jnp.asarray(st_depth),
-        st_map=jnp.asarray(st_map),
-        st_used=jnp.asarray(st_used),
-        st_cand=jnp.asarray(st_cand),
-        base=jnp.zeros((v,), jnp.int32),
-        size=jnp.asarray(size),
-        matches=jnp.zeros((v,), jnp.int32),
-        states=jnp.zeros((v,), jnp.int32),
-        exp_depth=jnp.zeros((v,), jnp.int32),
-        steals=jnp.zeros((v,), jnp.int32),
-        steal_depth=jnp.zeros((v,), jnp.int32),
-        steal_rounds=jnp.zeros((), jnp.int32),
-        steps=jnp.zeros((), jnp.int32),
-        overflow=jnp.zeros((), jnp.bool_),
-        match_buf=jnp.full((v, mcap, p_pad), -1, jnp.int32),
-    )
+def deal_seeds(
+    plan: SearchPlan,
+    cfg: "EngineConfig",
+    seed_depth: np.ndarray,
+    seed_map: np.ndarray,
+    seed_cand: np.ndarray,
+) -> Seeds:
+    """Deal partial-embedding entries round-robin over the ``V`` workers.
+
+    ``seed_depth [K]`` / ``seed_map [K, p_pad]`` / ``seed_cand [K, w]``
+    must already be engine-valid (`repro.core.extend.host_cand_bitmap`
+    semantics: candidate bits are trusted, never re-checked).  Each
+    worker's block is padded to a power of two rows (at most the ring), so
+    seed batches of similar size share one traced shape; the caller chunks
+    ``K`` so no worker exceeds the stack capacity.
+    """
+    v = cfg.n_workers
+    p_pad, w = plan.p_pad, plan.w
+    s_cap = cfg.resolved_stack_cap(p_pad)
+    k = int(seed_depth.shape[0])
+    per_worker = -(-k // v) if k else 0
+    if per_worker > s_cap - 1:
+        raise ValueError(
+            f"{k} delta seeds over {v} workers exceed stack_cap={s_cap}; "
+            "chunk the seed batch"
+        )
+    rows = min(1 << (max(per_worker, 1) - 1).bit_length(), s_cap)
+    i = np.arange(k)
+    wk, slot = i % v, i // v
+    depth = np.zeros((v, rows), dtype=np.int32)
+    map_ = np.full((v, rows, p_pad), -1, dtype=np.int32)
+    cand = np.zeros((v, rows, w), dtype=np.uint32)
+    depth[wk, slot] = seed_depth
+    map_[wk, slot] = seed_map
+    cand[wk, slot] = seed_cand
+    size = np.bincount(wk, minlength=v).astype(np.int32)
+    return Seeds(cand=cand, depth=depth, map=map_, size=size)
 
 
 def init_delta_state(
@@ -386,54 +424,85 @@ def init_delta_state(
 
     Instead of :func:`init_state`'s depth-0 root split, worker stacks start
     from the given partial-embedding entries — one per inserted target edge
-    anchored onto a pattern edge.  ``seed_depth [K]`` / ``seed_map [K,
-    p_pad]`` / ``seed_cand [K, w]`` must already be engine-valid
-    (`repro.core.extend.host_cand_bitmap` semantics: candidate bits are
-    trusted, never re-checked).  Seeds are dealt round-robin across the
-    ``V`` workers; the caller chunks ``K`` so no worker exceeds the stack
-    capacity.
+    anchored onto a pattern edge — dealt by :func:`deal_seeds`.
     """
-    v = cfg.n_workers
-    p_pad, w = plan.p_pad, plan.w
+    seeds = deal_seeds(plan, cfg, seed_depth, seed_map, seed_cand)
+    return seeded_state(cfg, plan.p_pad, seeds)
+
+
+def without_rows(seeds: Seeds) -> Seeds:
+    """``seeds``' shapes holding no entry: a run from them stops at once."""
+    return seeds._replace(
+        cand=np.zeros_like(seeds.cand),
+        size=None if seeds.size is None else np.zeros_like(seeds.size),
+    )
+
+
+def stack_seeds(lanes: Sequence[Seeds], pack: int) -> Seeds:
+    """One pack's seeds, lane ``i`` holding ``lanes[i]`` and the rest of
+    the ``pack`` lanes none (inert lanes, which the vmapped loop leaves at
+    once).  Lanes share one form: vertex roots, unless some lane is dealt
+    rows; then every lane is dealt rows, as many as the widest lane's."""
+    if all(s.depth is None for s in lanes):
+        cand = np.zeros((pack,) + lanes[0].cand.shape, np.uint32)
+        for i, s in enumerate(lanes):
+            cand[i] = s.cand
+        return Seeds(cand=cand)
+    v, _, w = lanes[0].cand.shape
+    rows = max(s.cand.shape[1] for s in lanes)
+    p_pad = next(s.map.shape[-1] for s in lanes if s.map is not None)
+    depth = np.zeros((pack, v, rows), np.int32)
+    map_ = np.full((pack, v, rows, p_pad), -1, np.int32)
+    cand = np.zeros((pack, v, rows, w), np.uint32)
+    size = np.zeros((pack, v), np.int32)
+    for i, s in enumerate(lanes):
+        r = s.cand.shape[1]
+        cand[i, :, :r] = s.cand
+        if s.depth is None:
+            size[i] = s.cand[:, 0].any(axis=1)
+        else:
+            depth[i, :, :r] = s.depth
+            map_[i, :, :r] = s.map
+            size[i] = s.size
+    return Seeds(cand=cand, depth=depth, map=map_, size=size)
+
+
+def seed_shape(seeds: Seeds) -> tuple:
+    """What a pack's seeds add to its engine's shape: the dealt rows per
+    worker, or nothing for vertex roots."""
+    return () if seeds.depth is None else (seeds.cand.shape[-2],)
+
+
+def state_from_seeds(cfg: "EngineConfig", p_pad: int, seeds: Seeds) -> EngineState:
+    """Traceable: the rings of one run (unbatched :class:`Seeds`), empty
+    but for the seed rows at slots ``0..R-1`` of each worker, and zeroed
+    counters.  A dealt row's used-bitmap is its mapped prefix
+    (:func:`used_from_map`)."""
+    v, r, w = seeds.cand.shape
     s_cap = cfg.resolved_stack_cap(p_pad)
     mcap = max(1, cfg.collect_matches)
-
-    seed_depth = np.asarray(seed_depth, dtype=np.int32)
-    seed_map = np.asarray(seed_map, dtype=np.int32)
-    seed_cand = np.asarray(seed_cand, dtype=np.uint32)
-    k = int(seed_depth.shape[0])
-    per_worker = -(-k // v) if k else 0
-    if per_worker > s_cap - 1:
-        raise ValueError(
-            f"{k} delta seeds over {v} workers exceed stack_cap={s_cap}; "
-            "chunk the seed batch"
-        )
-
-    st_depth = np.zeros((v, s_cap), dtype=np.int32)
-    st_map = np.full((v, s_cap, p_pad), -1, dtype=np.int32)
-    st_used = np.zeros((v, s_cap, w if cfg.store_used else 1), dtype=np.uint32)
-    st_cand = np.zeros((v, s_cap, w), dtype=np.uint32)
-    size = np.zeros((v,), dtype=np.int32)
-    for i in range(k):
-        wk = i % v
-        slot = size[wk]
-        st_depth[wk, slot] = seed_depth[i]
-        st_map[wk, slot] = seed_map[i]
-        st_cand[wk, slot] = seed_cand[i]
+    st_depth = jnp.zeros((v, s_cap), jnp.int32)
+    st_map = jnp.full((v, s_cap, p_pad), -1, jnp.int32)
+    st_used = jnp.zeros((v, s_cap, w if cfg.store_used else 1), jnp.uint32)
+    st_cand = jnp.zeros((v, s_cap, w), jnp.uint32).at[:, :r].set(seeds.cand)
+    if seeds.depth is None:
+        size = jnp.any(seeds.cand[:, 0] != 0, axis=1).astype(jnp.int32)
+    else:
+        st_depth = st_depth.at[:, :r].set(seeds.depth)
+        st_map = st_map.at[:, :r].set(seeds.map)
         if cfg.store_used:
-            prefix = seed_map[i, : seed_depth[i]].astype(np.int64)
-            st_used[wk, slot] = bitmap_from_indices(
-                prefix[prefix >= 0], plan.n_t, w
+            used = jax.vmap(jax.vmap(lambda m, d: used_from_map(m, d, w)))(
+                seeds.map, seeds.depth
             )
-        size[wk] = slot + 1
-
+            st_used = st_used.at[:, :r].set(used)
+        size = jnp.asarray(seeds.size, jnp.int32)
     return EngineState(
-        st_depth=jnp.asarray(st_depth),
-        st_map=jnp.asarray(st_map),
-        st_used=jnp.asarray(st_used),
-        st_cand=jnp.asarray(st_cand),
+        st_depth=st_depth,
+        st_map=st_map,
+        st_used=st_used,
+        st_cand=st_cand,
         base=jnp.zeros((v,), jnp.int32),
-        size=jnp.asarray(size),
+        size=size,
         matches=jnp.zeros((v,), jnp.int32),
         states=jnp.zeros((v,), jnp.int32),
         exp_depth=jnp.zeros((v,), jnp.int32),
@@ -444,6 +513,11 @@ def init_delta_state(
         overflow=jnp.zeros((), jnp.bool_),
         match_buf=jnp.full((v, mcap, p_pad), -1, jnp.int32),
     )
+
+
+# state_from_seeds as one device program ``(cfg, p_pad, seeds)``, for the
+# paths that hand the engine a whole state (single runs, meshes, legs)
+seeded_state = jax.jit(state_from_seeds, static_argnums=(0, 1))
 
 
 # ---------------------------------------------------------------------------

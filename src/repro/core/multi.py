@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import engine as eng
+from repro.core import frontier
 from repro.core.engine import EngineConfig
 from repro.core.graph import Graph
 from repro.core.plan import SearchPlan
@@ -50,15 +51,15 @@ def run_batch(plans: Sequence[SearchPlan], cfg: EngineConfig):
     Deprecated: prefer :meth:`Enumerator.run_batch`, which adds LPT
     balancing, bucket grouping and compile caching."""
     stacked = _stack_plans(plans, cfg)
-    states = jax.tree.map(
-        lambda *xs: jnp.stack(xs), *[eng.init_state(p, cfg) for p in plans]
-    )
+    seeds = frontier.stack_seeds([frontier.seed_rows(p, cfg) for p in plans], len(plans))
+    p_pad = plans[0].p_pad
 
     @jax.jit
-    def go(plan_arrays, st):
-        return jax.vmap(lambda pl, s: eng._engine_loop(cfg, pl, s))(plan_arrays, st)
+    def go(plan_arrays, sd):
+        return jax.vmap(lambda pl, s: eng._engine_loop(
+            cfg, pl, frontier.state_from_seeds(cfg, p_pad, s)))(plan_arrays, sd)
 
-    return jax.block_until_ready(go(stacked, states))
+    return jax.block_until_ready(go(stacked, seeds))
 
 
 def enumerate_many(

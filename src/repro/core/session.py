@@ -821,11 +821,13 @@ class Enumerator:
             self._adj_device.pop(fingerprint, None)
             return len(stale)
 
-    def _engine_fn(self, cfg: EngineConfig, kind: str, pack: int, query: Query) -> Callable:
+    def _engine_fn(self, cfg: EngineConfig, kind: str, pack: int, query: Query,
+                   seed_shape: tuple = ()) -> Callable:
         # layout check first: an explicitly dense backend against a
         # CSR-only plan must raise *before* a compile is spent/counted
         extend.validate_backend_for_plan(cfg, query.plan)
-        shape_key = (cfg, kind, pack, eng.mesh_signature(self.mesh)) + query.bucket
+        shape_key = ((cfg, kind, pack, eng.mesh_signature(self.mesh))
+                     + query.bucket + seed_shape)
         resolved = eng.resolve_step_backend_for_plan(cfg, query.plan)
         if resolved == "csr":
             # csr plan arrays carry density-dependent shapes (deg_cap, nnz);
@@ -869,7 +871,7 @@ class Enumerator:
                     loop.__name__ = "_engine_loop"  # device program jit__engine_loop
                     fn = jax.jit(loop)
             else:
-                fn = jax.jit(jax.vmap(functools.partial(eng._engine_loop, cfg)))
+                fn = eng.make_pack_engine_fn(cfg, query.plan.p_pad)
             with self._cache_lock:
                 self._traces[shape_key] = fn
         self._cache_put(key, fn)
@@ -1209,7 +1211,8 @@ class Enumerator:
                 fn = self._engine_fn(runc, "part", 1, q)
                 pp = extend.plan_partitions(q.plan, runc.n_partitions)
                 arrays = extend.make_part_plan_arrays(q.plan, pp, 0)
-                st = _inert_state(eng.init_state(q.plan, runc))
+                st = frontier.seeded_state(runc, q.plan.p_pad, frontier.without_rows(
+                    frontier.seed_rows(q.plan, runc)))
                 spill = frontier.init_spill_state(
                     runc.n_workers,
                     runc.resolved_spill_cap(q.plan.p_pad),
@@ -1218,18 +1221,19 @@ class Enumerator:
                 )
                 jax.block_until_ready(fn(arrays, st, spill))
             elif lanes > 1 and self.mesh is None:
-                # the pack path stacks per-lane arrays/states; an all-inert
-                # pack of the dispatch width traces the same vmapped engine
-                fn = self._engine_fn(cfg, "batch", lanes, q)
+                # an all-inert pack of the dispatch width, seeded in the
+                # query's own form, traces the same vmapped engine
+                seeds = frontier.stack_seeds(
+                    [frontier.without_rows(frontier.seed_rows(q.plan, cfg))], lanes)
+                fn = self._engine_fn(cfg, "batch", lanes, q, frontier.seed_shape(seeds))
                 arrays = eng.plan_arrays_for(cfg, q.plan)
-                st = _inert_state(eng.init_state(q.plan, cfg))
                 stacked = jax.tree.map(lambda x: jnp.stack([x] * lanes), arrays)
-                states = jax.tree.map(lambda x: jnp.stack([x] * lanes), st)
-                jax.block_until_ready(fn(stacked, states))
+                jax.block_until_ready(fn(stacked, seeds))
             else:
                 fn = self._engine_fn(cfg, "single", 1, q)
                 arrays = self._plan_arrays(cfg, q)
-                st = _inert_state(eng.init_state(q.plan, cfg))
+                st = frontier.seeded_state(cfg, q.plan.p_pad, frontier.without_rows(
+                    frontier.seed_rows(q.plan, cfg)))
                 jax.block_until_ready(fn(arrays, st))
             warmed += 1
         return {"warmed": warmed, "compiles": self.compiles - before}
@@ -1633,35 +1637,39 @@ class Enumerator:
     def _run_pack(
         self, members: List[int], qs: List[Query], cfg: EngineConfig, pack_size: int
     ) -> List[MatchSet]:
-        """Execute one padded pack of same-bucket queries."""
+        """Execute one padded pack of same-bucket queries: only the seed
+        rows go to the device, only the reduced counters come back."""
         t0 = time.perf_counter()
-        with trace.span("pack.build"):
+        n = len(members)
+        with trace.span("pack.build") as sp:
             plans = [qs[i].plan for i in members]
-            fn = self._engine_fn(cfg, "batch", pack_size, qs[members[0]])
+            # lanes past the members hold no seed rows: every pack of this
+            # bucket shares one compilation, and those lanes leave the
+            # vmapped while_loop at once
+            seeds = frontier.stack_seeds(
+                [frontier.seed_rows(p, cfg) for p in plans], pack_size)
+            fn = self._engine_fn(cfg, "batch", pack_size, qs[members[0]],
+                                 frontier.seed_shape(seeds))
             arrays = [eng.plan_arrays_for(cfg, p) for p in plans]
-            states = [eng.init_state(p, cfg) for p in plans]
-            # pad inert lanes so every pack of this bucket shares one
-            # compilation (size==0 lanes freeze immediately under the
-            # vmapped while_loop)
-            while len(arrays) < pack_size:
-                arrays.append(arrays[0])
-                states.append(_inert_state(states[0]))
+            arrays += [arrays[0]] * (pack_size - n)
             stacked_plan = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
-            stacked_state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+            if sp is not None:
+                sp.add(seed_bytes=sum(x[:n].nbytes for x in jax.tree.leaves(seeds)))
         with trace.span("pack.device") as sp:
-            final = jax.block_until_ready(fn(stacked_plan, stacked_state))
+            counters = jax.block_until_ready(fn(stacked_plan, seeds))
             if sp is not None:
                 # loop rounds each lane ran; the vmapped loop runs until
                 # its slowest lane stops
-                steps = np.asarray(final.steps)[: len(members)]
-                sp.add(occupied=len(members), steps_max=int(steps.max()),
+                steps = np.asarray(counters.steps)[:n]
+                sp.add(occupied=n, steps_max=int(steps.max()),
                        steps_sum=int(steps.sum()))
-        match_s = (time.perf_counter() - t0) / max(len(members), 1)
+        match_s = (time.perf_counter() - t0) / max(n, 1)
         out = []
         with trace.span("pack.decode"):
+            host = jax.device_get(counters)
             for row, i in enumerate(members):
-                lane = jax.tree.map(lambda x, r=row: x[r], final)
-                res = eng.result_from_state(lane, cfg)
+                res = eng.result_from_counters(
+                    jax.tree.map(lambda x, r=row: x[r], host))
                 if res.overflow:
                     # the pack undercounted this lane; go straight to the
                     # doubled-stack_cap single retry (re-running at the
@@ -1745,14 +1753,3 @@ def _predict_work(plan: SearchPlan) -> float:
     ``core/multi.py`` heuristic feeding LPT pack balancing)."""
     sizes = popcount(plan.dom_bits[: min(plan.n_p, 4)])
     return float(np.prod(np.maximum(sizes, 1), dtype=np.float64))
-
-
-def _inert_state(template: eng.EngineState) -> eng.EngineState:
-    """A copy of ``template`` with no work: size 0, empty candidate bitmaps.
-
-    Used to pad packs to a fixed lane count; the vmapped while_loop freezes
-    these lanes immediately, so they cost nothing but shape stability."""
-    return template._replace(
-        size=jnp.zeros_like(template.size),
-        st_cand=jnp.zeros_like(template.st_cand),
-    )

@@ -138,3 +138,29 @@ def test_jnp_engine_loop_compiles_for_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes)
     assert total < V5E_HBM, total
+
+
+def test_pack_engine_compiles_for_v5e(one_chip):
+    """The served pack program at the Human target's width (4,674 nodes)
+    over 4 lanes of ``configs.sge.ENGINE`` fits one chip's HBM, takes only
+    plan arrays and one root bitmap per worker, and returns only counters:
+    the worker rings live and die inside it."""
+    n_t, lanes = 4674, 4
+    w = _words(n_t)
+    plan = extend.abstract_plan_arrays(n_t, w, P_PAD, MAX_PARENTS)
+    seeds = frontier.Seeds(
+        cand=jax.ShapeDtypeStruct((ENGINE.n_workers, 1, w), jnp.uint32))
+    stack = functools.partial(
+        jax.tree.map,
+        lambda s: jax.ShapeDtypeStruct((lanes,) + tuple(s.shape), s.dtype,
+                                       sharding=one_chip))
+    compiled = eng.make_pack_engine_fn(ENGINE, P_PAD).lower(
+        stack(plan), stack(seeds)).compile()
+    mem = compiled.memory_analysis()
+    nbytes = sum(s.size * s.dtype.itemsize
+                 for s in jax.tree.leaves((stack(plan), stack(seeds))))
+    assert mem.argument_size_in_bytes <= 1.05 * nbytes  # tiled layouts pad
+    assert mem.output_size_in_bytes < lanes * ENGINE.n_workers * 64
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < V5E_HBM, total

@@ -1,8 +1,11 @@
 """Prepared-query session API: compile-cache behaviour, run/run_batch/stream
 agreement with the sequential oracle, and wrapper-vs-session equivalence."""
 
+import functools
 import pickle
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,10 +17,13 @@ from repro.core import (
     prepare_query,
     snap_p_pad,
 )
-from repro.core.graph import Graph
+from repro.core import engine as eng
+from repro.core import frontier
+from repro.core.graph import Graph, PackedGraph, bitmap_from_indices
 from repro.core.multi import enumerate_many
+from repro.core.plan import build_plan
 from repro.core.ref import ref_enumerate
-from tests.conftest import extract_connected_pattern, random_graph
+from tests.conftest import extract_connected_pattern, power_law_target, random_graph
 
 CFG = EngineConfig(n_workers=4, expand_width=2)
 
@@ -310,3 +316,148 @@ def test_overflow_raises_when_doubled_cap_still_too_small(rng):
     with pytest.warns(RuntimeWarning, match="overflowed"):
         with pytest.raises(RuntimeError, match="stack overflow persists"):
             s.run(s.prepare(pat))
+
+
+# ---------------------------------------------------------------------------
+# device-seeded packs against the state built whole on the host
+# ---------------------------------------------------------------------------
+
+def _host_built_state(plan, cfg):
+    """The initial state built whole in numpy, rings and all, as the engine
+    was seeded before packs seeded on the device: the reference here."""
+    v, p_pad, w = cfg.n_workers, plan.p_pad, plan.w
+    s_cap = cfg.resolved_stack_cap(p_pad)
+    st_depth = np.zeros((v, s_cap), np.int32)
+    st_map = np.full((v, s_cap, p_pad), -1, np.int32)
+    st_used = np.zeros((v, s_cap, w if cfg.store_used else 1), np.uint32)
+    st_cand = np.zeros((v, s_cap, w), np.uint32)
+    size = np.zeros(v, np.int32)
+    mode = cfg.root_seeding
+    if mode == "auto":
+        mode = "edge" if plan.seed_edge is not None else "vertex"
+    root_mask = None
+    if mode == "edge":
+        sd, sm, sc = frontier.root_seed_entries(plan)
+        if -(-len(sd) // v) > s_cap - 1:
+            root_mask = bitmap_from_indices(sm[:, 0].astype(np.int64), plan.n_t, w)
+        else:
+            for i in range(len(sd)):
+                wk = i % v
+                slot = size[wk]
+                st_depth[wk, slot] = sd[i]
+                st_map[wk, slot] = sm[i]
+                st_cand[wk, slot] = sc[i]
+                if cfg.store_used:
+                    prefix = sm[i, : sd[i]].astype(np.int64)
+                    st_used[wk, slot] = bitmap_from_indices(
+                        prefix[prefix >= 0], plan.n_t, w)
+                size[wk] = slot + 1
+    if mode == "vertex" or root_mask is not None:
+        splits = np.linspace(0, plan.n_t, v + 1).astype(np.int64)
+        for k in range(v):
+            idx = np.arange(splits[k], splits[k + 1])
+            if idx.size:
+                st_cand[k, 0] = bitmap_from_indices(idx, plan.n_t, w) & plan.dom_bits[0]
+        if root_mask is not None:
+            st_cand[:, 0] &= root_mask
+        if not plan.satisfiable:
+            st_cand[:, 0] = 0
+        size = st_cand[:, 0].any(axis=1).astype(np.int32)
+    zeros = np.zeros(v, np.int32)
+    return eng.EngineState(
+        st_depth=st_depth, st_map=st_map, st_used=st_used, st_cand=st_cand,
+        base=zeros, size=size, matches=zeros, states=zeros, exp_depth=zeros,
+        steals=zeros, steal_depth=zeros, steal_rounds=np.int32(0),
+        steps=np.int32(0), overflow=np.bool_(False),
+        match_buf=np.full((v, max(1, cfg.collect_matches), p_pad), -1, np.int32),
+    )
+
+
+def _host_result(final):
+    """An EngineResult reduced in numpy from a whole final state."""
+    f = jax.device_get(final)
+    steals, states = int(f.steals.sum()), int(f.states.sum())
+    return eng.EngineResult(
+        matches=int(f.matches.sum()), states=states, steps=int(f.steps),
+        steals=steals, steal_rounds=int(f.steal_rounds),
+        mean_steal_depth=int(f.steal_depth.sum()) / steals if steals else 0.0,
+        mean_expand_depth=int(f.exp_depth.sum()) / states if states else 0.0,
+        per_worker_states=f.states, per_worker_matches=f.matches,
+        overflow=bool(f.overflow), match_buf=f.match_buf,
+        per_worker_steals=f.steals,
+    )
+
+
+def _pack_scenario(rng, scenario):
+    """``(plans of one shape, cfg keywords, pack width)``."""
+    shape = dict(p_pad=16, max_parents=8)
+    if scenario in ("root_mask_fallback", "edge_seeded"):
+        tgt = power_law_target(rng, 420, avg_deg=3.5, alpha=1.7, n_labels=8)
+    else:
+        tgt = random_graph(rng, 40, 120, n_labels=3)
+    pk = PackedGraph.from_graph(tgt)
+    pats = [extract_connected_pattern(rng, tgt, 4) for _ in range(3)]
+    plans = [build_plan(p, pk, **shape) for p in pats]
+    if scenario == "one_lane":
+        return plans[:1], {}, 1
+    if scenario == "two_lanes_one_inert":
+        return plans[:1], {}, 2
+    if scenario == "four_lanes_one_inert":
+        return plans, {}, 4
+    if scenario == "unsatisfiable_lane":
+        bad = Graph.from_edges(3, [(0, 1), (1, 2)], labels=[99, 0, 1],
+                               undirected=True)
+        unsat = build_plan(bad, pk, **shape)
+        assert not unsat.satisfiable
+        return [plans[0], unsat], {}, 2
+    eplans = [build_plan(p, pk, seed_edge="auto", **shape) for p in pats[:2]]
+    if scenario == "edge_seeded":
+        # a dealt-rows lane beside a vertex-seeded one, and an inert lane
+        return [eplans[0], plans[1], eplans[1]], {"root_seeding": "auto"}, 4
+    # two workers whose rings hold fewer rows than the seed class deals
+    # each: seeding falls back to the root split under the seed-class mask
+    k = len(frontier.root_seed_entries(eplans[0])[0])
+    assert k >= 4
+    kw = {"root_seeding": "edge", "n_workers": 2, "stack_cap": -(-k // 2)}
+    return eplans[:1], kw, 2
+
+
+@pytest.mark.parametrize("scenario", [
+    "one_lane", "two_lanes_one_inert", "four_lanes_one_inert",
+    "unsatisfiable_lane", "root_mask_fallback", "edge_seeded"])
+@pytest.mark.parametrize("backend", ["jnp", "csr"])
+def test_device_seeded_pack_equals_host_built_state(rng, backend, scenario):
+    """A pack seeded on the device from seed rows and reduced there gives,
+    lane for lane, every EngineResult field of a run from the state built
+    whole on the host, match buffer order included; its seeded state equals
+    that state field by field, and its inert lanes do nothing."""
+    plans, kw, pack = _pack_scenario(rng, scenario)
+    cfg = EngineConfig(**{"n_workers": 4, "expand_width": 2, "step_backend": backend,
+                          "collect_matches": 64, **kw})
+    lanes = [frontier.seed_rows(p, cfg) for p in plans]
+    if scenario == "root_mask_fallback":
+        assert lanes[0].depth is None
+    if scenario == "edge_seeded":
+        assert [s.depth is None for s in lanes] == [False, True, False]
+    loop = jax.jit(functools.partial(eng._engine_loop, cfg))
+    want = []
+    for p in plans:
+        host = _host_built_state(p, cfg)
+        jax.tree.map(np.testing.assert_array_equal,
+                     jax.device_get(frontier.init_state(p, cfg)), host)
+        want.append(_host_result(loop(eng.plan_arrays_for(cfg, p), host)))
+
+    arrays = [eng.plan_arrays_for(cfg, p) for p in plans]
+    arrays += [arrays[0]] * (pack - len(plans))
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
+    seeds = frontier.stack_seeds(lanes, pack)
+    counters = jax.device_get(
+        eng.make_pack_engine_fn(cfg, plans[0].p_pad)(stacked, seeds))
+    for row in range(pack):
+        got = eng.result_from_counters(jax.tree.map(lambda x: x[row], counters))
+        if row >= len(plans):
+            assert (got.matches, got.states, got.steps) == (0, 0, 0)
+            continue
+        for field in eng.EngineResult._fields:
+            np.testing.assert_array_equal(
+                getattr(got, field), getattr(want[row], field), err_msg=field)

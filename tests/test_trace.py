@@ -186,3 +186,21 @@ def test_served_path_spans(recorder, rng):
     stats = svc.stats()
     assert 0 <= stats["queue_wait_p50_s"] <= stats["queue_wait_p99_s"]
     assert "uptime_s" not in stats and "latency_mean_s" not in stats
+
+
+def test_pack_build_counts_seed_bytes(recorder, rng):
+    """Under vertex seeding a pack hands the engine one root bitmap per
+    worker for each occupied lane: ``seed_bytes`` on ``pack.build`` is
+    occupied lanes × V × w × 4, whatever the pack's width."""
+    enum, pats = _served(rng, n_queries=1)
+    q = enum.prepare(pats[0])
+    assert q.plan.satisfiable
+    recorder.drain()
+    v, w = enum.config.n_workers, q.plan.w
+    for occupied, width in ((1, 1), (1, 4), (3, 4)):
+        enum.run_pack([q] * occupied, pack_size=width)
+        spans = recorder.drain()
+        (build,) = [s for s in spans if s.name == "pack.build"]
+        (device,) = [s for s in spans if s.name == "pack.device"]
+        assert device.counts["occupied"] == occupied
+        assert build.counts["seed_bytes"] == occupied * v * w * 4
